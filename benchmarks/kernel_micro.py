@@ -35,9 +35,9 @@ def _paged_decode_leg(key) -> dict:
     for bh, n_pages in ((1, 2), (4, 4), (8, 8), (16, 16)):
         ks = jax.random.split(key, 5)
         pool_pages = n_pages * 2  # pool larger than any one table
-        k_pool = jax.random.normal(ks[0], (pool_pages, page, hd), jnp.bfloat16)
-        v_pool = jax.random.normal(ks[1], (pool_pages, page, hd), jnp.bfloat16)
-        q = jax.random.normal(ks[2], (bh, hd), jnp.bfloat16)
+        k_pool = jax.random.normal(ks[0], (1, pool_pages, page, hd), jnp.bfloat16)
+        v_pool = jax.random.normal(ks[1], (1, pool_pages, page, hd), jnp.bfloat16)
+        q = jax.random.normal(ks[2], (bh, 1, hd), jnp.bfloat16)
         table = jax.random.randint(ks[3], (bh, n_pages), 0, pool_pages)
         lens = jax.random.randint(ks[4], (bh,), 1, n_pages * page + 1)
         out, us = _time(
@@ -64,11 +64,11 @@ def _paged_decode_int8_leg(key) -> dict:
     page, hd, bh, n_pages = 16, 64, 8, 8
     ks = jax.random.split(key, 5)
     pool_pages = n_pages * 2
-    kf = jax.random.normal(ks[0], (pool_pages, page, hd), jnp.float32)
-    vf = jax.random.normal(ks[1], (pool_pages, page, hd), jnp.float32)
-    kq, ksc = jax.vmap(quantize)(kf)
-    vq, vsc = jax.vmap(quantize)(vf)
-    q = jax.random.normal(ks[2], (bh, hd), jnp.float32)
+    kf = jax.random.normal(ks[0], (1, pool_pages, page, hd), jnp.float32)
+    vf = jax.random.normal(ks[1], (1, pool_pages, page, hd), jnp.float32)
+    kq, ksc = jax.vmap(jax.vmap(quantize))(kf)
+    vq, vsc = jax.vmap(jax.vmap(quantize))(vf)
+    q = jax.random.normal(ks[2], (bh, 1, hd), jnp.float32)
     table = jax.random.randint(ks[3], (bh, n_pages), 0, pool_pages)
     lens = jax.random.randint(ks[4], (bh,), 1, n_pages * page + 1)
     out, us = _time(

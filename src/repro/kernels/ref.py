@@ -106,39 +106,42 @@ def ssd_scan_ref(
 
 
 def paged_decode_attention_ref(
-    q: jax.Array,  # [BH, hd]
-    k_pool: jax.Array,  # [n_pages, page, hd]
+    q: jax.Array,  # [B, H, hd]
+    k_pool: jax.Array,  # [KV, n_pages, page, hd]
     v_pool: jax.Array,
-    page_table: jax.Array,  # [BH, max_pages]
-    seq_lens: jax.Array,  # [BH]
+    page_table: jax.Array,  # [B, max_pages]
+    seq_lens: jax.Array,  # [B]
 ) -> jax.Array:
     """Gather-based oracle: materialize each request's KV then attend."""
-    bh, hd = q.shape
-    page = k_pool.shape[1]
+    b, h, hd = q.shape
+    kv, _, page, _ = k_pool.shape
     max_pages = page_table.shape[1]
-    k = k_pool[page_table].reshape(bh, max_pages * page, hd)
-    v = v_pool[page_table].reshape(bh, max_pages * page, hd)
+    head_kv = jnp.arange(h) // (h // kv)  # GQA: q head → its kv head
+    k = k_pool[:, page_table].reshape(kv, b, max_pages * page, hd)[head_kv]
+    v = v_pool[:, page_table].reshape(kv, b, max_pages * page, hd)[head_kv]
     scale = 1.0 / math.sqrt(hd)
     s = jnp.einsum(
-        "bd,bkd->bk", q.astype(jnp.float32), k.astype(jnp.float32)
+        "bhd,hbkd->bhk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
-    tok = jnp.arange(max_pages * page)[None, :]
-    s = jnp.where(tok < seq_lens[:, None], s, NEG_INF)
+    tok = jnp.arange(max_pages * page)[None, None, :]
+    s = jnp.where(tok < seq_lens[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bk,bkd->bd", p, v.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum(
+        "bhk,hbkd->bhd", p, v.astype(jnp.float32)
+    ).astype(q.dtype)
 
 
 def paged_decode_attention_int8_ref(
-    q: jax.Array,  # [BH, hd]
-    k_pool: jax.Array,  # [n_pages, page, hd] int8 codes
+    q: jax.Array,  # [B, H, hd]
+    k_pool: jax.Array,  # [KV, n_pages, page, hd] int8 codes
     v_pool: jax.Array,
-    k_scales: jax.Array,  # [n_pages] f32
+    k_scales: jax.Array,  # [KV, n_pages] f32
     v_scales: jax.Array,
-    page_table: jax.Array,  # [BH, max_pages]
-    seq_lens: jax.Array,  # [BH]
+    page_table: jax.Array,  # [B, max_pages]
+    seq_lens: jax.Array,  # [B]
 ) -> jax.Array:
     """Dequantize the whole pool up front, then run the f32 oracle — the
     exact two-pass flow the in-kernel dequant is meant to eliminate."""
-    k = k_pool.astype(jnp.float32) * k_scales[:, None, None]
-    v = v_pool.astype(jnp.float32) * v_scales[:, None, None]
+    k = k_pool.astype(jnp.float32) * k_scales[..., None, None]
+    v = v_pool.astype(jnp.float32) * v_scales[..., None, None]
     return paged_decode_attention_ref(q, k, v, page_table, seq_lens)

@@ -9,6 +9,7 @@ pass ``--full`` on real hardware.
 import argparse
 
 from repro.configs import ARCHS, SHAPES, get_arch
+from repro.launch import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train import Trainer, TrainerConfig
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if not args.full:
         cfg = cfg.smoke()

@@ -628,13 +628,13 @@ def paged_decode_supported(cfg: ArchConfig) -> bool:
 
 
 def _quantize_pool_int8(pool: Array):
-    """Per-page absmax int8 quantization of a ``[n, P, hd]`` pool view:
-    returns (codes int8, scales f32 [n]) in the layout
+    """Per-page absmax int8 quantization of a ``[KV, n, P, hd]`` pool view:
+    returns (codes int8, scales f32 [KV, n]) in the layout
     :func:`kernels.ops.paged_decode_attention_int8` consumes.  The scale
     floor keeps all-zero (never-written pad) pages from dividing by 0."""
-    absmax = jnp.max(jnp.abs(pool), axis=(1, 2))
+    absmax = jnp.max(jnp.abs(pool), axis=(2, 3))
     scales = jnp.maximum(absmax / 127.0, 1e-8).astype(jnp.float32)
-    codes = jnp.round(pool / scales[:, None, None]).astype(jnp.int8)
+    codes = jnp.round(pool / scales[..., None, None]).astype(jnp.int8)
     return codes, scales
 
 
@@ -651,7 +651,6 @@ def decode_step_paged(
     page_src_idx: Array,  # [n_pool] int32 logical page index in that slot
     *,
     page_tokens: int,
-    n_pool: int,
     interpret: bool,
     int8: bool = False,
 ):
@@ -664,15 +663,11 @@ def decode_step_paged(
 
     The per-slot dense caches remain the storage of truth (COW, tier
     promotion and migration all operate on them); this step materializes
-    the *pool view* the kernel wants by gathering each live pool page from
-    its owning slot via the provenance arrays, then runs ONE kernel call
-    per layer with the kv-head axis folded into the page axis:
-
-        pool row of (kv head g, page pid) = g · n_pool + pid
-        table row of (request b, q head h) = table[b] + (h // G) · n_pool
-
-    so a [B, W] block table becomes [B·H, W] and the whole active batch is
-    a single (B·H, W) grid.  Rows are expected sorted by length
+    the *pool view* the kernel wants — ``[KV, n_pool, P, hd]`` — by
+    gathering each live pool page from its owning slot via the provenance
+    arrays, then runs ONE kernel call per layer over a (B, H, W) grid: the
+    kernel maps query head h to kv head h // G itself, so the [B, W] block
+    table is shared by every head.  Rows are expected sorted by length
     (descending) and W trimmed to the longest resident request — short
     decodes then never pay DMAs for the long tail.  New-token K/V are
     scatter-written into the slot caches *before* the gather (matching the
@@ -685,16 +680,7 @@ def decode_step_paged(
     x = _embed(cfg, params, tokens)
     B = tokens.shape[0]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    G = H // KV
     P = page_tokens
-
-    # per-q-head rows of the folded table: identical for every layer
-    hoff = (jnp.arange(H, dtype=jnp.int32) // G) * n_pool
-    table_flat = (
-        jnp.repeat(page_table.astype(jnp.int32), H, axis=0)
-        + jnp.tile(hoff, B)[:, None]
-    )
-    lens_flat = jnp.repeat(seq_lens.astype(jnp.int32), H)
     positions = poss[:, None, None]  # [..., s] with s == 1
 
     def attn_block(p, x_in, cache):
@@ -716,19 +702,19 @@ def decode_step_paged(
         vcr = vcp.reshape(n_slots, KV, lp, P, hd)
         k_pool = kcr[page_src_slot, :, page_src_idx]  # [n_pool, KV, P, hd]
         v_pool = vcr[page_src_slot, :, page_src_idx]
-        k_pool = k_pool.transpose(1, 0, 2, 3).reshape(KV * n_pool, P, hd)
-        v_pool = v_pool.transpose(1, 0, 2, 3).reshape(KV * n_pool, P, hd)
-        qf = q[:, :, 0, :].reshape(B * H, hd)
+        k_pool = k_pool.transpose(1, 0, 2, 3)  # [KV, n_pool, P, hd]
+        v_pool = v_pool.transpose(1, 0, 2, 3)
+        qf = q[:, :, 0, :]  # [B, H, hd]
         if int8:
             k_codes, k_scales = _quantize_pool_int8(k_pool)
             v_codes, v_scales = _quantize_pool_int8(v_pool)
             out = kernel_ops.paged_decode_attention_int8(
                 qf, k_codes, v_codes, k_scales, v_scales,
-                table_flat, lens_flat, interpret=interpret,
+                page_table, seq_lens, interpret=interpret,
             )
         else:
             out = kernel_ops.paged_decode_attention(
-                qf, k_pool, v_pool, table_flat, lens_flat,
+                qf, k_pool, v_pool, page_table, seq_lens,
                 interpret=interpret,
             )
         out = out.reshape(B, 1, H * hd)

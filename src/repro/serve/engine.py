@@ -632,25 +632,23 @@ class ServingEngine:
             if ecfg.kernel_interpret is not None
             else jax.default_backend() != "tpu"
         )
-        self.paged_decode_ticks = 0  # decode ticks served by the kernel
+        self.decode_ticks = 0  # ticks that decoded at least one row
+        self.paged_decode_ticks = 0  # … of which served by the kernel
         self.paged_int8_ticks = 0  # … of which through the int8 kernel
 
         def _paged_step(
             params, caches, tok, row_slot, poss, tables, lens,
-            src_slot, src_idx, n_pool,
+            src_slot, src_idx,
         ):
             logits, new_caches = decode_step_paged(
                 cfg, params, tok, caches, poss, row_slot, tables, lens,
                 src_slot, src_idx, page_tokens=self.kv.page_tokens,
-                n_pool=n_pool, interpret=self._kernel_interpret,
-                int8=self._paged_int8,
+                interpret=self._kernel_interpret, int8=self._paged_int8,
             )
             # batch argmax on device: ONE transfer back per tick
             return jnp.argmax(logits[:, 0, :], axis=-1), new_caches
 
-        self._decode_paged = jax.jit(
-            _paged_step, static_argnums=(9,), donate_argnums=(1,)
-        )
+        self._decode_paged = jax.jit(_paged_step, donate_argnums=(1,))
 
     # ----------------------------------------------------- live bookkeeping
     def _set_state(self, req: Request, new: str) -> None:
@@ -1864,18 +1862,16 @@ class ServingEngine:
             active.append((i, self.requests[rid]))
         if not active:
             return 0.0
+        self.decode_ticks += 1
         self._tick_decode_tokens = len(active)
         kv_bytes_read = sum(
             self.kv.request_bytes(req.request_id) for _, req in active
         )
         if self._paged_ok and self.kv.n_pages > 0:
-            try:
-                nxt = self._decode_paged_batch(active)
-            except ValueError:
-                # a running request briefly overlaps an in-flight demotion
-                # (its table carries DEMOTED ids): the dense slot caches
-                # still hold every value, so fall back for this tick
-                nxt = self._decode_dense_batch(active)
+            # every active row passed the residency gate above, so no
+            # table carries a demoted id and gather_plan cannot refuse it;
+            # a kernel error is an error, never a reason to decode dense
+            nxt = self._decode_paged_batch(active)
         else:
             nxt = self._decode_dense_batch(active)
         for r, (i, req) in enumerate(active):
@@ -1978,7 +1974,7 @@ class ServingEngine:
             (tok, row_slot, poss, tab, lens, src_slot, src_idx)
         )
         nxt, self._caches = self._decode_paged(
-            self.params, self._caches, *staged, n_pool2
+            self.params, self._caches, *staged
         )
         self.paged_decode_ticks += 1
         if self._paged_int8:

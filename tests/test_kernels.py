@@ -162,25 +162,27 @@ class TestSSDScan:
 
 class TestPagedDecode:
     @given(
-        bh=st.integers(1, 4),
+        b=st.integers(1, 3),
+        heads=st.sampled_from([(1, 1), (2, 1), (4, 2)]),  # (H, KV): GQA
         max_pages=st.integers(1, 4),
         page=st.sampled_from([16, 32]),
         hd=st.sampled_from([32, 64]),
         seed=st.integers(0, 100),
     )
     @settings(max_examples=12, deadline=None)
-    def test_matches_gather_oracle(self, bh, max_pages, page, hd, seed):
+    def test_matches_gather_oracle(self, b, heads, max_pages, page, hd, seed):
+        h, kv = heads
         key = jax.random.PRNGKey(seed)
         k1, k2, k3, k4, k5 = jax.random.split(key, 5)
-        n_pool = bh * max_pages + 3
-        q = _rand(k1, (bh, hd), jnp.float32)
-        k_pool = _rand(k2, (n_pool, page, hd), jnp.float32)
-        v_pool = _rand(k3, (n_pool, page, hd), jnp.float32)
+        n_pool = b * max_pages + 3
+        q = _rand(k1, (b, h, hd), jnp.float32)
+        k_pool = _rand(k2, (kv, n_pool, page, hd), jnp.float32)
+        v_pool = _rand(k3, (kv, n_pool, page, hd), jnp.float32)
         # random non-overlapping-ish page table + random valid lengths ≥ 1
-        table = jax.random.permutation(k4, n_pool)[: bh * max_pages].reshape(
-            bh, max_pages
+        table = jax.random.permutation(k4, n_pool)[: b * max_pages].reshape(
+            b, max_pages
         )
-        lens = jax.random.randint(k5, (bh,), 1, max_pages * page + 1)
+        lens = jax.random.randint(k5, (b,), 1, max_pages * page + 1)
         out = ops.paged_decode_attention(q, k_pool, v_pool, table, lens)
         gold = ref.paged_decode_attention_ref(q, k_pool, v_pool, table, lens)
         np.testing.assert_allclose(
@@ -225,9 +227,10 @@ class TestPagedDecode:
         seq = jnp.asarray([lens[r] for r in lens], jnp.int32)
         out = np.asarray(
             ops.paged_decode_attention(
-                q, jnp.asarray(k_pool), jnp.asarray(v_pool), table, seq
+                q[:, None], jnp.asarray(k_pool)[None],
+                jnp.asarray(v_pool)[None], table, seq,
             )
-        )
+        )[:, 0]
         # dense per-request oracle: softmax over the contiguous K/V prefix
         for i, (rid, n) in enumerate(lens.items()):
             kk = dense_k[rid][:n]
@@ -294,9 +297,10 @@ class TestPagedDecode:
         lens = jnp.asarray([50, 46], jnp.int32)
         out = np.asarray(
             ops.paged_decode_attention(
-                q, jnp.asarray(k_pool), jnp.asarray(v_pool), table, lens
+                q[:, None], jnp.asarray(k_pool)[None],
+                jnp.asarray(v_pool)[None], table, lens,
             )
-        )
+        )[:, 0]
         # oracle 1: dense per-request softmax over the contiguous prefix
         for i, (sk, sv, n) in enumerate(((sa_k, sa_v, 50), (sb_k, sb_v, 46))):
             s = np.asarray(q)[i] @ sk[:n].T / np.sqrt(hd)
@@ -311,38 +315,40 @@ class TestPagedDecode:
         table_dup[1, :2] = np.arange(n_pool, n_pool + 2)
         out_dup = np.asarray(
             ops.paged_decode_attention(
-                q, jnp.asarray(k2), jnp.asarray(v2),
+                q[:, None], jnp.asarray(k2)[None], jnp.asarray(v2)[None],
                 jnp.asarray(table_dup), lens,
             )
-        )
+        )[:, 0]
         np.testing.assert_allclose(out, out_dup, atol=1e-6)
 
 
 class TestPagedDecodeInt8:
     @given(
-        bh=st.integers(1, 4),
+        b=st.integers(1, 3),
+        heads=st.sampled_from([(1, 1), (2, 1), (4, 2)]),  # (H, KV): GQA
         max_pages=st.integers(1, 3),
         seed=st.integers(0, 50),
     )
     @settings(max_examples=8, deadline=None)
-    def test_matches_dequantize_first_oracle(self, bh, max_pages, seed):
+    def test_matches_dequantize_first_oracle(self, b, heads, max_pages, seed):
         """Dequantizing per-page int8 codes INSIDE the page sweep must
         match dequantizing the whole pool up front."""
         from repro.dist.compression import quantize
 
+        h, kv = heads
         page, hd = 16, 64
         key = jax.random.PRNGKey(seed)
         k1, k2, k3, k4, k5 = jax.random.split(key, 5)
-        n_pool = bh * max_pages + 2
-        q = _rand(k1, (bh, hd), jnp.float32)
-        kf = _rand(k2, (n_pool, page, hd), jnp.float32)
-        vf = _rand(k3, (n_pool, page, hd), jnp.float32)
-        kq, ks = jax.vmap(quantize)(kf)
-        vq, vs = jax.vmap(quantize)(vf)
-        table = jax.random.permutation(k4, n_pool)[: bh * max_pages].reshape(
-            bh, max_pages
+        n_pool = b * max_pages + 2
+        q = _rand(k1, (b, h, hd), jnp.float32)
+        kf = _rand(k2, (kv, n_pool, page, hd), jnp.float32)
+        vf = _rand(k3, (kv, n_pool, page, hd), jnp.float32)
+        kq, ks = jax.vmap(jax.vmap(quantize))(kf)
+        vq, vs = jax.vmap(jax.vmap(quantize))(vf)
+        table = jax.random.permutation(k4, n_pool)[: b * max_pages].reshape(
+            b, max_pages
         )
-        lens = jax.random.randint(k5, (bh,), 1, max_pages * page + 1)
+        lens = jax.random.randint(k5, (b,), 1, max_pages * page + 1)
         out = ops.paged_decode_attention_int8(
             q, kq, vq, ks, vs, table, lens
         )

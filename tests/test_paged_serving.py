@@ -78,6 +78,33 @@ class TestDecodeParity:
             assert paged.requests[rid].generated == \
                 dense.requests[rid].generated, f"{rid} tokens diverged"
 
+    def test_kernel_error_propagates_instead_of_dense_fallback(
+        self, small_model, monkeypatch
+    ):
+        """A ValueError from inside the jitted paged step (as a Mosaic
+        lowering error would raise) surfaces from step(): no tick decodes
+        on the dense path in its place."""
+        cfg, params = small_model
+        eng = ServingEngine(
+            cfg, params,
+            EngineConfig(n_slots=2, max_seq=32, hbm_capacity_bytes=1e12),
+        )
+
+        def broken_step(*_args, **_kw):
+            raise ValueError("kernel refused")
+
+        dense_calls = []
+        monkeypatch.setattr(eng, "_decode_paged", broken_step)
+        monkeypatch.setattr(
+            eng, "_decode_dense_batch", lambda active: dense_calls.append(1)
+        )
+        eng.submit(Request("r0", "A", list(range(5, 10)), 4))
+        with pytest.raises(ValueError, match="kernel refused"):
+            for _ in range(10):
+                eng.step()
+        assert not dense_calls
+        assert eng.decode_ticks == 1 and eng.paged_decode_ticks == 0
+
     def test_paged_engine_survives_unpaged_arch(self):
         """An ineligible arch (SSM blocks) silently keeps the dense path
         even when the flag asks for the kernel."""
