@@ -115,9 +115,10 @@ def _paged_kernel_int8(
 
 
 def _paged_call(kernel, q, k_pool, v_pool, page_table, seq_lens, scales,
-                *, interpret: bool):
+                *, name: str, interpret: bool):
     """Shared grid/BlockSpec wiring of the bf16/f32 and int8 kernels;
-    ``scales`` are extra scalar-prefetch operands after table and lens."""
+    ``scales`` are extra scalar-prefetch operands after table and lens.
+    ``name`` names the kernel's custom call in HLO and in device traces."""
     b, h, hd = q.shape
     kv, _, page, _ = k_pool.shape
     group = h // kv
@@ -152,6 +153,7 @@ def _paged_call(kernel, q, k_pool, v_pool, page_table, seq_lens, scales,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(page_table.astype(jnp.int32).reshape(-1), seq_lens.astype(jnp.int32),
       *scales, q[:, :, None, :], k_pool, v_pool)
     return out[:, :, 0, :]
@@ -175,7 +177,8 @@ def paged_decode_attention_int8(
     scales = (k_scales.astype(jnp.float32).reshape(-1),
               v_scales.astype(jnp.float32).reshape(-1))
     return _paged_call(kernel, q, k_pool, v_pool, page_table, seq_lens,
-                       scales, interpret=interpret)
+                       scales, name="paged_decode_attention_int8",
+                       interpret=interpret)
 
 
 def paged_decode_attention(
@@ -188,4 +191,5 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     return _paged_call(_paged_kernel, q, k_pool, v_pool, page_table,
-                       seq_lens, (), interpret=interpret)
+                       seq_lens, (), name="paged_decode_attention",
+                       interpret=interpret)

@@ -313,6 +313,7 @@ def moe_dispatch_row(x_row: Array, gates_row: Array, top_k: int, capacity: int):
     return xe, slot, sorted_tok, sorted_gate, keep
 
 
+@jax.named_scope("moe_ffn")
 def moe_forward(p: dict, cfg: ArchConfig, x: Array) -> Array:
     """Token-choice top-k MoE with per-sequence capacity (GShard-style
     token dropping) and sort-based grouped dispatch.

@@ -673,6 +673,9 @@ def decode_step_paged(
     scatter-written into the slot caches *before* the gather (matching the
     dense path, which attends positions ``<= pos`` inclusive); pad rows
     carry ``row_slot == n_slots`` so their writes drop out-of-bounds.
+    In device traces the layer scan runs under the named scope
+    ``layers`` and each block's parts under ``kv_write``, ``pool_view``,
+    ``attention``, ``wo`` and ``ffn``.
     Returns (logits [B, 1, V], updated caches).
     """
     from repro.kernels import ops as kernel_ops
@@ -690,37 +693,43 @@ def decode_step_paged(
         q = apply_rope(q, positions, cfg.rope_theta)
         k_new = apply_rope(k_new, positions, cfg.rope_theta)
         kc, vc = cache  # [n_slots, KV, max_seq, hd]
-        kc = kc.at[row_slot, :, poss].set(k_new[:, :, 0, :], mode="drop")
-        vc = vc.at[row_slot, :, poss].set(v_new[:, :, 0, :], mode="drop")
+        with jax.named_scope("kv_write"):
+            kc = kc.at[row_slot, :, poss].set(k_new[:, :, 0, :], mode="drop")
+            vc = vc.at[row_slot, :, poss].set(v_new[:, :, 0, :], mode="drop")
         # pool view: pad seq to whole pages, gather page provenance
-        n_slots, _, max_seq, _ = kc.shape
-        lp = -(-max_seq // P)
-        pad = lp * P - max_seq
-        kcp = jnp.pad(kc, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else kc
-        vcp = jnp.pad(vc, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else vc
-        kcr = kcp.reshape(n_slots, KV, lp, P, hd)
-        vcr = vcp.reshape(n_slots, KV, lp, P, hd)
-        k_pool = kcr[page_src_slot, :, page_src_idx]  # [n_pool, KV, P, hd]
-        v_pool = vcr[page_src_slot, :, page_src_idx]
-        k_pool = k_pool.transpose(1, 0, 2, 3)  # [KV, n_pool, P, hd]
-        v_pool = v_pool.transpose(1, 0, 2, 3)
+        with jax.named_scope("pool_view"):
+            n_slots, _, max_seq, _ = kc.shape
+            lp = -(-max_seq // P)
+            pad = lp * P - max_seq
+            kcp = jnp.pad(kc, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else kc
+            vcp = jnp.pad(vc, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else vc
+            kcr = kcp.reshape(n_slots, KV, lp, P, hd)
+            vcr = vcp.reshape(n_slots, KV, lp, P, hd)
+            k_pool = kcr[page_src_slot, :, page_src_idx]  # [n_pool, KV, P, hd]
+            v_pool = vcr[page_src_slot, :, page_src_idx]
+            k_pool = k_pool.transpose(1, 0, 2, 3)  # [KV, n_pool, P, hd]
+            v_pool = v_pool.transpose(1, 0, 2, 3)
+            if int8:
+                k_codes, k_scales = _quantize_pool_int8(k_pool)
+                v_codes, v_scales = _quantize_pool_int8(v_pool)
         qf = q[:, :, 0, :]  # [B, H, hd]
-        if int8:
-            k_codes, k_scales = _quantize_pool_int8(k_pool)
-            v_codes, v_scales = _quantize_pool_int8(v_pool)
-            out = kernel_ops.paged_decode_attention_int8(
-                qf, k_codes, v_codes, k_scales, v_scales,
-                page_table, seq_lens, interpret=interpret,
-            )
-        else:
-            out = kernel_ops.paged_decode_attention(
-                qf, k_pool, v_pool, page_table, seq_lens,
-                interpret=interpret,
-            )
+        with jax.named_scope("attention"):
+            if int8:
+                out = kernel_ops.paged_decode_attention_int8(
+                    qf, k_codes, v_codes, k_scales, v_scales,
+                    page_table, seq_lens, interpret=interpret,
+                )
+            else:
+                out = kernel_ops.paged_decode_attention(
+                    qf, k_pool, v_pool, page_table, seq_lens,
+                    interpret=interpret,
+                )
         out = out.reshape(B, 1, H * hd)
-        y = jnp.einsum("bsh,hd->bsd", out, ap["wo"])
+        with jax.named_scope("wo"):
+            y = jnp.einsum("bsh,hd->bsd", out, ap["wo"])
         x_out = x_in + y
-        x_out = x_out + _ffn(p, cfg, x_out)
+        with jax.named_scope("ffn"):
+            x_out = x_out + _ffn(p, cfg, x_out)
         return x_out, (kc, vc)
 
     def unit_fn(h, inputs):
@@ -731,9 +740,12 @@ def decode_step_paged(
             new_c[f"b{i}"] = c
         return h, new_c
 
-    x, new_unit = jax.lax.scan(
-        unit_fn, x, {"p": params["layers"], "c": caches["unit"]}
-    )
+    # the scan's own ops (slicing each layer's cache out of the stack and
+    # writing it back) land under this scope, outside the block's scopes
+    with jax.named_scope("layers"):
+        x, new_unit = jax.lax.scan(
+            unit_fn, x, {"p": params["layers"], "c": caches["unit"]}
+        )
     new_suffix = []
     for p_blk, c_blk in zip(params["suffix"], caches["suffix"]):
         x, c = attn_block(p_blk, x, c_blk)

@@ -559,6 +559,7 @@ class ServingCluster:
         req.state = "queued"
         req.finish_tick = -1
         req.first_token_tick = -1
+        req.t_admit = req.t_first = req.t_finish = None
         req.cached_tokens = 0
         req.snap_key = None
         req.hit_counted = False
@@ -673,6 +674,7 @@ class ServingCluster:
         pos_at_crash = req.pos
         req.slot = -1
         req.finish_tick = -1
+        req.t_finish = None
         req.cached_tokens = 0
         req.snap_key = None
         req.pos = int(entry["pos"])

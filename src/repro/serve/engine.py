@@ -60,11 +60,13 @@ prefixes evict under pressure in LRU order crossed with the policy's
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 import numpy as np
 
@@ -138,6 +140,13 @@ class Request:
     #: with a typed ``wrong_model`` outcome instead of silently decoding
     #: through the wrong weights
     model: str = ""
+    #: host-clock stamps (``time.perf_counter``, seconds): submitted, first
+    #: given a batch row, first generated token appended, finished or
+    #: failed.  None until the event happens
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_finish: Optional[float] = None
 
     @property
     def total_tokens(self) -> int:
@@ -161,6 +170,20 @@ class Request:
     @property
     def prefilling(self) -> bool:
         return self.pos < len(self.feed_tokens)
+
+
+def _seconds(req: Request) -> Dict[str, Optional[float]]:
+    """A request's host-clock stamps as :class:`RequestOutcome` seconds
+    since its submission."""
+
+    def since(t: Optional[float]) -> Optional[float]:
+        return None if t is None or req.t_submit is None else t - req.t_submit
+
+    return {
+        "queue_s": since(req.t_admit),
+        "ttft_s": since(req.t_first),
+        "latency_s": since(req.t_finish),
+    }
 
 
 @dataclass
@@ -567,12 +590,18 @@ class ServingEngine:
             ),
             donate_argnums=(2,),
         )
+        # named scopes mark each program's ops in device traces; the
+        # decorator keeps the function's name, so the program names
+        # (jit__lambda, jit__chunk_scan) stay as they were
         self._prefill = jax.jit(
-            lambda params, tokens: prefill(
-                cfg, params, tokens, max_seq=ecfg.max_seq, remat=False
+            jax.named_scope("prefill")(
+                lambda params, tokens: prefill(
+                    cfg, params, tokens, max_seq=ecfg.max_seq, remat=False
+                )
             )
         )
 
+        @jax.named_scope("chunk_scan")
         def _chunk_scan(params, tokens, caches, slot, pos0):
             """Advance ONE slot by ``len(tokens)`` prompt tokens in a
             single device dispatch (scan over the shared decode_step) —
@@ -697,6 +726,7 @@ class ServingEngine:
         engine never rejects at the door — put a
         :class:`repro.serve.frontdoor.FrontDoor` in front for that)."""
         req.submit_tick = self.tick
+        req.t_submit = time.perf_counter()
         if not req.model:
             req.model = self.cfg.name
         self.requests[req.request_id] = req
@@ -709,6 +739,7 @@ class ServingEngine:
             self.misroutes += 1
             req.state = "failed"
             req.finish_tick = self.tick
+            req.t_finish = time.perf_counter()
             req.fail_reason = (
                 f"wrong_model: replica hosts {self.cfg.name!r}, "
                 f"request targets {req.model!r}"
@@ -983,8 +1014,7 @@ class ServingEngine:
                 if self.kv.free_pages < pages_needed:
                     continue  # no headroom yet: land on a later tick
             slot = free_slots.pop(0)
-            req.slot = slot
-            self._slot_req[slot] = rid
+            self._seat(req, slot)
             self.kv.grow_to(rid, max(req.pos, 1))
             if ticket.slot_cache is not None:
                 self._install_slot(slot, ticket.slot_cache)
@@ -1365,8 +1395,16 @@ class ServingEngine:
         ]
 
     # ------------------------------------------------------------ admission
-    def _admit(self) -> None:
-        """Admit queued requests while slots and prompt headroom allow.
+    def _seat(self, req: Request, slot: int) -> None:
+        """Give ``req`` batch row ``slot``, stamping its first admission."""
+        req.slot = slot
+        self._slot_req[slot] = req.request_id
+        if req.t_admit is None:
+            req.t_admit = time.perf_counter()
+
+    def _admit(self) -> int:
+        """Admit queued requests while slots and prompt headroom allow;
+        returns how many requests were given a batch row.
 
         A request that does not fit WAITS at the door (stock continuous-
         batching semantics: block until KV pages free up) — for every
@@ -1376,6 +1414,7 @@ class ServingEngine:
         completions or pays the reactive spill path.
         """
         free_slots = [i for i, r in enumerate(self._slot_req) if r is None]
+        n_free = len(free_slots)
         # migrated-in requests land first (their KV installs from the
         # ticket, no replay), then local restores
         self._land_imports(free_slots)
@@ -1409,9 +1448,7 @@ class ServingEngine:
                         req.feed_tokens
                     )
                 req.hit_counted = True
-            slot = free_slots.pop(0)
-            req.slot = slot
-            self._slot_req[slot] = req.request_id
+            self._seat(req, free_slots.pop(0))
             self._set_state(req, "prefill")
             req.pos = 0
             self._frozen_payloads.pop(req.request_id, None)
@@ -1475,6 +1512,7 @@ class ServingEngine:
                     by_tenant[tenant].pop(0)
                 self._set_state(req, "failed")
                 req.finish_tick = self.tick
+                req.t_finish = time.perf_counter()
                 req.fail_reason = "prompt exceeds admission headroom"
                 self.failed.append(req.request_id)
                 self._drop_live(req)
@@ -1522,12 +1560,11 @@ class ServingEngine:
                     req.request_id, req.feed_tokens, float(self.tick)
                 )
             self.kv.grow_to(req.request_id, len(req.prompt))
-            slot = free_slots.pop(0)
-            req.slot = slot
-            self._slot_req[slot] = req.request_id
+            self._seat(req, free_slots.pop(0))
             self._set_state(req, "prefill")
             req.pos = 0
             self._update_pool()
+        return n_free - len(free_slots)
 
     # --------------------------------------------------------- slot caches
     def _extract_slot(self, slot: int) -> Dict[str, Any]:
@@ -1690,10 +1727,12 @@ class ServingEngine:
             # next decode step feeds generated[-1] — nothing new to sample
             self._set_state(req, "decoding")
             return
-        next_tok = int(jnp.argmax(last_logits))
+        with TraceAnnotation("engine.prefill.first_token", rid=req.request_id):
+            next_tok = int(jnp.argmax(last_logits))
         self._publish_prefix(req, next_tok)
         req.generated.append(next_tok)
         req.first_token_tick = self.tick
+        req.t_first = time.perf_counter()
         self._set_state(req, "decoding")
 
     def _publish_prefix(self, req: Request, first_tok: int) -> None:
@@ -1750,6 +1789,7 @@ class ServingEngine:
             else:
                 req.generated.append(first_tok)
                 req.first_token_tick = self.tick
+                req.t_first = time.perf_counter()
                 self._set_state(req, "decoding")
         else:
             # partial hit (or full-page hit needing last-position logits):
@@ -1786,7 +1826,8 @@ class ServingEngine:
                 # from the snapshot — no prefill compute, no budget, so
                 # this runs even when a long cold prefill drained the
                 # budget (an exact hit must never queue behind compute)
-                self._install_cached_prefix(req)
+                with TraceAnnotation("engine.prefill.prefix_hit", rid=rid):
+                    self._install_cached_prefix(req)
                 if req.state != "prefill":
                     continue  # exact hit: first token already sampled
             if budget <= 0:
@@ -1796,7 +1837,10 @@ class ServingEngine:
                 if len(feed) <= budget:
                     self.kv.grow_to(rid, len(feed))
                     self._cow_range(req, 0, len(feed))
-                    logits = self._install_prefill(req, feed)
+                    with TraceAnnotation(
+                        "engine.prefill.install", rid=rid, tokens=len(feed)
+                    ):
+                        logits = self._install_prefill(req, feed)
                     budget -= len(feed)
                     self._tick_prefill_tokens += len(feed)
                     self._finish_prefill(req, logits)
@@ -1807,7 +1851,10 @@ class ServingEngine:
                     w = 1 << (budget.bit_length() - 1)
                     self.kv.grow_to(rid, w)
                     self._cow_range(req, 0, w)
-                    self._install_prefill(req, feed[:w])
+                    with TraceAnnotation(
+                        "engine.prefill.install", rid=rid, tokens=w
+                    ):
+                        self._install_prefill(req, feed[:w])
                     budget -= w
                     self._tick_prefill_tokens += w
                     chunked = True
@@ -1824,15 +1871,20 @@ class ServingEngine:
                     )
                 # power-of-two buckets: O(log chunk) dispatches per tick
                 # and a bounded set of compiled scan widths
-                while take > 0:
-                    w = 1 << (take.bit_length() - 1)
-                    toks = jnp.asarray(feed[req.pos:req.pos + w], jnp.int32)
-                    last, self._caches = self._chunk_scan(
-                        self.params, toks, self._caches, req.slot,
-                        jnp.int32(req.pos),
-                    )
-                    req.pos += w
-                    take -= w
+                with TraceAnnotation(
+                    "engine.prefill.chunk", rid=rid, tokens=take, pos=req.pos
+                ):
+                    while take > 0:
+                        w = 1 << (take.bit_length() - 1)
+                        toks = jnp.asarray(
+                            feed[req.pos:req.pos + w], jnp.int32
+                        )
+                        last, self._caches = self._chunk_scan(
+                            self.params, toks, self._caches, req.slot,
+                            jnp.int32(req.pos),
+                        )
+                        req.pos += w
+                        take -= w
                 chunked = True
                 if not req.prefilling and last is not None:
                     self._finish_prefill(req, last)
@@ -1847,52 +1899,65 @@ class ServingEngine:
         KV bytes the tick's attention read (the roofline's HBM traffic
         term), derived from the ledger's per-owner attribution — not a
         separately maintained tally."""
-        active = []
-        for i, rid in enumerate(self._slot_req):
-            if rid is None or self.requests[rid].state != "decoding":
-                continue
-            if not self.kv.resident(rid):
-                # tokens on overflow or demoted pages are not in HBM —
-                # attention cannot read them; the request stalls until
-                # reclaim() / promotion pages them back in
-                self.stall_ticks += 1
-                if self.kv.has_demoted(rid):
-                    self.transfer_stall_ticks += 1
-                continue
-            active.append((i, self.requests[rid]))
-        if not active:
-            return 0.0
-        self.decode_ticks += 1
-        self._tick_decode_tokens = len(active)
-        kv_bytes_read = sum(
-            self.kv.request_bytes(req.request_id) for _, req in active
-        )
-        if self._paged_ok and self.kv.n_pages > 0:
-            # every active row passed the residency gate above, so no
-            # table carries a demoted id and gather_plan cannot refuse it;
-            # a kernel error is an error, never a reason to decode dense
-            nxt = self._decode_paged_batch(active)
+        with TraceAnnotation("engine.decode.plan") as span:
+            active = []
+            for i, rid in enumerate(self._slot_req):
+                if rid is None or self.requests[rid].state != "decoding":
+                    continue
+                if not self.kv.resident(rid):
+                    # tokens on overflow or demoted pages are not in HBM —
+                    # attention cannot read them; the request stalls until
+                    # reclaim() / promotion pages them back in
+                    self.stall_ticks += 1
+                    if self.kv.has_demoted(rid):
+                        self.transfer_stall_ticks += 1
+                    continue
+                active.append((i, self.requests[rid]))
+            if not active:
+                return 0.0
+            self.decode_ticks += 1
+            self._tick_decode_tokens = len(active)
+            kv_bytes_read = sum(
+                self.kv.request_bytes(req.request_id) for _, req in active
+            )
+            paged = self._paged_ok and self.kv.n_pages > 0
+            if paged:
+                # every active row passed the residency gate above, so no
+                # table carries a demoted id and gather_plan cannot refuse
+                # it; a kernel error is an error, never a reason to decode
+                # dense
+                order, host = self._stage_paged_batch(active)
+            span.set_metadata(
+                rows=len(active),
+                width=host[3].shape[1] if paged else 0,
+                # positions the rows attend: the kernel's K/V traffic
+                kv_tokens=sum(req.pos + 1 for _, req in active),
+            )
+        if paged:
+            nxt = self._decode_paged_batch(active, order, host)
         else:
             nxt = self._decode_dense_batch(active)
-        for r, (i, req) in enumerate(active):
-            req.pos += 1
-            self.kv.grow_to(req.request_id, req.pos)
-            # the KV write landed at position pos-1: if that page is shared
-            # (an exact-prompt hit decoding past its cached terminal page),
-            # split it first — shared pages are never mutated.  The paged
-            # path addressed this page through a synthetic pool id, so
-            # this is the FIRST allocator mutation either way: both decode
-            # paths drive the same allocator event sequence.
-            self.kv.make_private(
-                req.request_id, (req.pos - 1) // self.kv.page_tokens
-            )
-            self.kv.note_write(
-                req.request_id, req.pos - 1, req.pos, self.tick
-            )
-            req.generated.append(int(nxt[r]))
-            if req.done:
-                self._finish(req)
-        self._update_pool()
+        with TraceAnnotation("engine.decode.bookkeep"):
+            for r, (i, req) in enumerate(active):
+                req.pos += 1
+                self.kv.grow_to(req.request_id, req.pos)
+                # the KV write landed at position pos-1: if that page is
+                # shared (an exact-prompt hit decoding past its cached
+                # terminal page), split it first — shared pages are never
+                # mutated.  The paged path addressed this page through a
+                # synthetic pool id, so this is the FIRST allocator
+                # mutation either way: both decode paths drive the same
+                # allocator event sequence.
+                self.kv.make_private(
+                    req.request_id, (req.pos - 1) // self.kv.page_tokens
+                )
+                self.kv.note_write(
+                    req.request_id, req.pos - 1, req.pos, self.tick
+                )
+                req.generated.append(int(nxt[r]))
+                if req.done:
+                    self._finish(req)
+            self._update_pool()
         return kv_bytes_read
 
     def _decode_dense_batch(self, active) -> np.ndarray:
@@ -1911,24 +1976,30 @@ class ServingEngine:
             tokens[i, 0] = req.generated[-1]
             poss[i] = req.pos
             mask[i] = True
-        tokens, poss, mask = jax.device_put((tokens, poss, mask))
-        logits, self._caches = self._decode_all(
-            self.params, tokens, self._caches, poss, mask
-        )
-        nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+        with TraceAnnotation("engine.decode.put"):
+            tokens, poss, mask = jax.device_put((tokens, poss, mask))
+        with TraceAnnotation("engine.decode.launch"):
+            logits, self._caches = self._decode_all(
+                self.params, tokens, self._caches, poss, mask
+            )
+            nxt = jnp.argmax(logits[:, 0, :], axis=-1)
+        with TraceAnnotation("engine.decode.wait"):
+            nxt = np.asarray(nxt)
         return nxt[[i for i, _ in active]]
 
-    def _decode_paged_batch(self, active):
-        """One decode tick through the paged Pallas kernel.
+    def _stage_paged_batch(self, active):
+        """Host-side inputs of one paged decode tick: returns the rows in
+        kernel order (longest first) and the numpy arrays
+        ``(tokens, row slots, positions, page tables, lengths, pool page →
+        slot, pool page → page index)``.
 
         Batches every active row's LIVE page table (the same tables the
-        byte accounting runs on) into a single ``decode_step_paged`` call:
-        rows sorted longest-first, table width and pool bound trimmed to
-        powers of two (``kv.gather_plan``), pad rows carrying an
-        out-of-bounds slot so their writes drop.  Returns next tokens
-        aligned with ``active`` order — the sort exists only to trim the
-        kernel grid; bookkeeping (and the order-sensitive finish→resume
-        chain) must see the same row order as the dense oracle.
+        byte accounting runs on): rows sorted longest-first, table width
+        and pool bound trimmed to powers of two (``kv.gather_plan``), pad
+        rows carrying an out-of-bounds slot so their writes drop.  The
+        sort exists only to trim the kernel grid; bookkeeping (and the
+        order-sensitive finish→resume chain) must see the same row order
+        as the dense oracle, which :meth:`_decode_paged_batch` restores.
         """
         P = self.kv.page_tokens
         # longest first: the trimmed width follows row 0, so the kernel's
@@ -1970,22 +2041,31 @@ class ServingEngine:
             tab[r, wp] = sid
             src_slot[sid] = slot
             src_idx[sid] = wp
-        staged = jax.device_put(
-            (tok, row_slot, poss, tab, lens, src_slot, src_idx)
-        )
-        nxt, self._caches = self._decode_paged(
-            self.params, self._caches, *staged
-        )
+        return order, (tok, row_slot, poss, tab, lens, src_slot, src_idx)
+
+    def _decode_paged_batch(self, active, order, host):
+        """One decode tick through the paged Pallas kernel: ship the
+        arrays :meth:`_stage_paged_batch` built for rows ``order`` in one
+        ``device_put``, run ``decode_step_paged`` once, and return the next
+        tokens aligned with ``active`` order."""
+        with TraceAnnotation("engine.decode.put"):
+            staged = jax.device_put(host)
+        with TraceAnnotation("engine.decode.launch"):
+            nxt, self._caches = self._decode_paged(
+                self.params, self._caches, *staged
+            )
         self.paged_decode_ticks += 1
         if self._paged_int8:
             self.paged_int8_ticks += 1
-        nxt = np.asarray(nxt)
+        with TraceAnnotation("engine.decode.wait"):
+            nxt = np.asarray(nxt)
         row_of = {slot: r for r, (slot, _) in enumerate(order)}
         return nxt[[row_of[slot] for slot, _ in active]]
 
     def _finish(self, req: Request) -> None:
         self._set_state(req, "done")
         req.finish_tick = self.tick
+        req.t_finish = time.perf_counter()
         self.completed.append(req.request_id)
         self._drop_live(req)
         self._release_slot(req)
@@ -1998,8 +2078,11 @@ class ServingEngine:
             self._resume(rid)
 
     # ----------------------------------------------------------------- policy
-    def _policy_pass(self) -> None:
+    def _policy_pass(self) -> Tuple[int, int]:
+        """Run the policy over the live requests; returns how many it
+        suspended and how many resumes it asked for."""
         active = self._active()
+        suspensions0 = self.suspensions
         for r in active:
             self.sampler.observe(
                 r.request_id,
@@ -2047,6 +2130,7 @@ class ServingEngine:
                 self._release_slot(req)
         for rid in decision.resume:
             self._resume(rid)
+        return self.suspensions - suspensions0, len(decision.resume)
 
     def _release_slot(self, req: Request) -> None:
         """Free the request's batch row (its KV pages stay accounted) — in
@@ -2070,38 +2154,58 @@ class ServingEngine:
     # ----------------------------------------------------------------- tick
     def step(self) -> None:
         """Advance one tick: admit, prefill a chunk, decode the batch,
-        then the policy/demotion passes; updates ``last_tick_cost``."""
-        stalls0 = self.stall_ticks
-        self._tick_prefill_tokens = 0
-        self._tick_decode_tokens = 0
-        self._admit()
-        self._prefill_tick()
-        kv_bytes_read = self._decode_tick()
-        # roofline-derived tick service time (modeled seconds): bytes
-        # moved this tick — weight stream + the KV pages of the requests
-        # actually decoded + prefill writes — over HBM bandwidth, vs
-        # FLOPs over peak, plus one PCIe page DMA per stall.  Straggler
-        # detection, placement scoring and the overload bench inherit
-        # hardware-meaningful units from here (deterministic — no wall
-        # clock in the simulation).
-        cost = self._tick_cost_model.tick_seconds(
-            decode_tokens=self._tick_decode_tokens,
-            prefill_tokens=self._tick_prefill_tokens,
-            kv_bytes_read=kv_bytes_read,
-            stall_events=self.stall_ticks - stalls0,
-        )
-        self.last_tick_cost = cost
-        self._tick_cost_count += 1
-        self._tick_cost_sum += cost
-        self._tick_cost_min = min(self._tick_cost_min, cost)
-        self._tick_cost_max = max(self._tick_cost_max, cost)
-        if len(self._tick_cost_values) < 64:
-            self._tick_cost_values.add(round(cost, 15))
-        period_ticks = max(
-            round(self.policy.period * self.ecfg.murs_period_ticks), 1
-        )
-        if self.tick % period_ticks == 0:
-            self._policy_pass()
+        then the policy/demotion passes; updates ``last_tick_cost``.
+
+        Each phase runs inside a ``jax.profiler.TraceAnnotation``
+        (``engine.step`` and its ``engine.*`` children), which records
+        only while a profiler session is active."""
+        with TraceAnnotation("engine.step", tick=self.tick):
+            stalls0 = self.stall_ticks
+            self._tick_prefill_tokens = 0
+            self._tick_decode_tokens = 0
+            with TraceAnnotation("engine.admit") as span:
+                span.set_metadata(admitted=self._admit())
+            with TraceAnnotation("engine.prefill") as span:
+                self._prefill_tick()
+                span.set_metadata(tokens=self._tick_prefill_tokens)
+            with TraceAnnotation("engine.decode") as span:
+                kv_bytes_read = self._decode_tick()
+                span.set_metadata(rows=self._tick_decode_tokens)
+            # roofline-derived tick service time (modeled seconds): bytes
+            # moved this tick — weight stream + the KV pages of the
+            # requests actually decoded + prefill writes — over HBM
+            # bandwidth, vs FLOPs over peak, plus one PCIe page DMA per
+            # stall.  Straggler detection, placement scoring and the
+            # overload bench inherit hardware-meaningful units from here
+            # (deterministic — no wall clock in the simulation).
+            cost = self._tick_cost_model.tick_seconds(
+                decode_tokens=self._tick_decode_tokens,
+                prefill_tokens=self._tick_prefill_tokens,
+                kv_bytes_read=kv_bytes_read,
+                stall_events=self.stall_ticks - stalls0,
+            )
+            self.last_tick_cost = cost
+            self._tick_cost_count += 1
+            self._tick_cost_sum += cost
+            self._tick_cost_min = min(self._tick_cost_min, cost)
+            self._tick_cost_max = max(self._tick_cost_max, cost)
+            if len(self._tick_cost_values) < 64:
+                self._tick_cost_values.add(round(cost, 15))
+            period_ticks = max(
+                round(self.policy.period * self.ecfg.murs_period_ticks), 1
+            )
+            if self.tick % period_ticks == 0:
+                with TraceAnnotation("engine.policy") as span:
+                    suspended, resumed = self._policy_pass()
+                    span.set_metadata(suspended=suspended, resumed=resumed)
+            with TraceAnnotation("engine.tiers"):
+                self._tiers_pass()
+            self.tick += 1
+
+    def _tiers_pass(self) -> None:
+        """The end of a tick: proactive demotion, overcommit, the tier
+        hierarchy's completed transfers, promotion, reclaim and snapshot
+        pruning."""
         self._proactive_demotion()
         self._resolve_overcommit()
         # advance the tier hierarchy: completed promotions swap back into
@@ -2122,7 +2226,6 @@ class ServingEngine:
             live = self.kv.live_snap_keys()
             self._snaps = {k: v for k, v in self._snaps.items() if k in live}
             self._pruned_at_evictions = self.kv.cache_evictions
-        self.tick += 1
 
     def _frozen_victims(self, require_pressure: bool) -> List[Request]:
         """Suspended requests whose frozen KV may demote, best victim
@@ -2389,6 +2492,7 @@ class ServingEngine:
     def _fail(self, victim: Request) -> None:
         self._set_state(victim, "failed")
         victim.finish_tick = self.tick
+        victim.t_finish = time.perf_counter()
         victim.fail_reason = "pool overcommit with offload disabled (OOM)"
         self.failed.append(victim.request_id)
         self._drop_live(victim)
@@ -2493,6 +2597,7 @@ class ServingEngine:
                         first_token_tick=r.first_token_tick,
                         tokens=len(r.generated),
                         model=r.model,
+                        **_seconds(r),
                     )
                 )
             elif r.state == "failed":
@@ -2507,6 +2612,7 @@ class ServingEngine:
                         tokens=len(r.generated),
                         reason=r.fail_reason,
                         model=r.model,
+                        **_seconds(r),
                     )
                 )
             else:
@@ -2520,6 +2626,7 @@ class ServingEngine:
                         tokens=len(r.generated),
                         reason=f"still {r.state} at tick budget",
                         model=r.model,
+                        **_seconds(r),
                     )
                 )
         rep = ServeReport(

@@ -99,6 +99,14 @@ class RequestOutcome:
     #: arch name the request targeted ("" = the run's single implicit
     #: model) — heterogeneous-fleet runs key per-model goodput on this
     model: str = ""
+    #: host-clock seconds from submission to the first batch row, to the
+    #: first token and to the end; None where the engine stamped no such
+    #: event (cluster and front-door rows carry none).  Wall-clock
+    #: readings differ from run to run, so they take no part in comparing
+    #: two outcomes
+    queue_s: Optional[float] = field(default=None, compare=False)
+    ttft_s: Optional[float] = field(default=None, compare=False)
+    latency_s: Optional[float] = field(default=None, compare=False)
 
     @property
     def latency_ticks(self) -> Optional[int]:
