@@ -909,6 +909,8 @@ def collect(debug: bool = False) -> dict:
             "p99_ticks_to_finish": _percentile(lat, 0.99),
             "ttft_p50_ticks": _percentile(out["ttft_ticks"], 0.50),
             "chunked_prefill_ticks": out["chunked_prefill_ticks"],
+            "chunk_prefill_calls": out["chunk_prefill_calls"],
+            "chunk_scan_calls": out["chunk_scan_calls"],
             "prefix_token_hit_rate": round(
                 out["prefix_cache"].get("token_hit_rate", 0.0), 3
             ),

@@ -9,6 +9,8 @@ from .transformer import (
     init_model,
     paged_decode_supported,
     prefill,
+    prefill_chunk,
+    prefill_chunk_supported,
 )
 
 __all__ = [
@@ -20,4 +22,6 @@ __all__ = [
     "init_model",
     "paged_decode_supported",
     "prefill",
+    "prefill_chunk",
+    "prefill_chunk_supported",
 ]

@@ -534,11 +534,18 @@ def mamba_forward(
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
     out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
     if return_cache:
+        # the conv's last d_conv − 1 inputs; a prompt shorter than that is
+        # preceded by the zeros the causal conv pads with
         tail = ssm.d_conv - 1
+        pad = ((0, 0), (max(tail - s, 0), 0), (0, 0))
+
+        def last(a):
+            return jnp.pad(a, pad)[:, -tail:, :]
+
         cache = (
-            x_in[:, -tail:, :],
-            B_in[:, -tail:, :],
-            C_in[:, -tail:, :],
+            last(x_in),
+            last(B_in),
+            last(C_in),
             _final_state(xs, dt, A, Bc),
         )
         return out, cache

@@ -24,6 +24,7 @@ from .layers import (
     apply_mlp,
     apply_rope,
     bf16_grad,
+    chunked_attention,
     dense_init,
     embed_init,
     init_mlp,
@@ -398,8 +399,6 @@ def _cross_attend(pc: dict, cfg: ArchConfig, x: Array, ctx_kv) -> Array:
     q = jnp.einsum("bsd,dh->bsh", h, pc["attn"]["wq"]).reshape(
         b, s, cfg.n_heads, hd
     ).transpose(0, 2, 1, 3)
-    from .layers import chunked_attention
-
     out = chunked_attention(q, k, v, causal=False)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return x + jnp.einsum("bsh,hd->bsd", out, pc["attn"]["wo"])
@@ -512,6 +511,99 @@ def prefill(
     if cross_ctx is not None:
         caches["cross_kv"] = cross_ctx
     return logits, caches
+
+
+def prefill_chunk_supported(cfg: ArchConfig) -> bool:
+    """True when :func:`prefill_chunk` serves ``cfg``: plain full attention
+    in every block (:func:`paged_decode_supported`) and a dense FFN.  A
+    MoE FFN stays on the per-token path: a ``w``-token dispatch meets an
+    expert capacity that one-token steps never hit."""
+    return paged_decode_supported(cfg) and cfg.moe is None
+
+
+def prefill_chunk(
+    cfg: ArchConfig,
+    params,
+    tokens: Array,  # [w] prompt tokens of one slot
+    caches,  # the engine's stacked slot caches (slot axis = n_slots)
+    slot: Array,  # scalar int32
+    pos0: Array,  # scalar int32 — position of tokens[0]
+):
+    """Continue one slot's prefill by ``w`` tokens as ONE ``[1, w]``
+    forward over its cache, so the weights stream once per chunk.
+
+    Per block, the ``w`` new K/V rows (RoPE at ``pos0 + arange(w)``) are
+    written into the slot's cache at ``pos0`` with a dynamic update slice
+    on the stacked caches, and the ``w`` queries attend the slot's whole
+    ``max_seq`` cache under ``key_pos <= pos0 + i``: the earlier positions
+    and causality inside the chunk, softmax in float32 as in
+    :func:`decode_step`.  Only the slot's rows move; every other slot and
+    every position outside the chunk keep their bits.  Serves the stacks
+    :func:`prefill_chunk_supported` admits.
+    Returns (last position's logits [V], updated caches).
+    """
+    w = tokens.shape[0]
+    x = _embed(cfg, params, tokens[None])
+    positions = (pos0 + jnp.arange(w, dtype=jnp.int32))[None, None, :]
+
+    def attn_block(p, x_in, cache, at):
+        """``at`` indexes the slot's ``[KV, max_seq, hd]`` in ``cache``:
+        (layer, slot) in the scanned unit, (slot,) in the suffix."""
+        h = rms_norm(x_in, p["ln1"], cfg.norm_eps)
+        q, k, v = blocks._qkv(p["attn"], cfg, h)  # [1, {H,KV}, w, hd]
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        lead = (1,) * len(at)
+        zero = jnp.int32(0)
+
+        def write_then_read(c, new):
+            new, start = new.reshape(lead + new.shape[1:]), pos0
+            if w == 1 and c.shape[-2] > 1:
+                # XLA lays the whole stacked cache out anew around a
+                # one-row update (two full copies in, two out); a two-row
+                # window whose other row keeps its own bits does not
+                start = jnp.minimum(pos0, c.shape[-2] - 2)
+                window = new.shape[:-2] + (2, new.shape[-1])
+                old = jax.lax.dynamic_slice(c, (*at, zero, start, zero), window)
+                is_new = (jnp.arange(2) == pos0 - start)[:, None]
+                new = jnp.where(is_new, new, old)
+            c = jax.lax.dynamic_update_slice(c, new, (*at, zero, start, zero))
+            mine = jax.lax.dynamic_slice(
+                c, (*at, zero, zero, zero), lead + c.shape[len(at):]
+            )
+            return c, mine.reshape((1,) + c.shape[len(at):])
+
+        kc, k_all = write_then_read(cache[0], k)
+        vc, v_all = write_then_read(cache[1], v)
+        out = chunked_attention(q, k_all, v_all, causal=True, q_offset=pos0)
+        out = out.transpose(0, 2, 1, 3).reshape(1, w, -1)
+        x_out = x_in + jnp.einsum("bsh,hd->bsd", out, p["attn"]["wo"])
+        x_out = x_out + _ffn(p, cfg, x_out)
+        return x_out, (kc, vc)
+
+    def unit_fn(carry, inputs):
+        h, unit_c = carry
+        unit_p, layer = inputs
+        new_c = {}
+        for i in range(len(cfg.block_pattern)):
+            h, new_c[f"b{i}"] = attn_block(
+                unit_p[f"b{i}"], h, unit_c[f"b{i}"], (layer, slot)
+            )
+        return (h, new_c), None
+
+    reps = cfg.resolved_pattern_repeats
+    (x, new_unit), _ = jax.lax.scan(
+        unit_fn,
+        (x, caches["unit"]),
+        (params["layers"], jnp.arange(reps, dtype=jnp.int32)),
+    )
+    new_suffix = []
+    for p_blk, c_blk in zip(params["suffix"], caches["suffix"]):
+        x, c = attn_block(p_blk, x, c_blk, (slot,))
+        new_suffix.append(c)
+
+    logits = _unembed(cfg, params, x[:, -1:])
+    return logits[0, 0], {"unit": new_unit, "suffix": new_suffix}
 
 
 def _pad_caches(cfg: ArchConfig, caches, max_seq: int):

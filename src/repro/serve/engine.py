@@ -42,10 +42,12 @@ consumed in token-budgeted chunks (``prefill_chunk_tokens`` per tick)
 interleaved with decode ticks, so one long prompt never stalls every
 in-flight decode the way a monolithic prefill call does.  Decode runs
 slot-batched: one jitted vmapped decode step advances every active slot per
-tick with per-slot positions; prefill continuation shares the same cache
-layout through a single-slot jitted step.  KV lives in the paged pool of
-:class:`PagedKVManager` — free-list block allocator, per-request page
-tables, the same tables the Pallas ``paged_decode`` kernel consumes.
+tick with per-slot positions; prefill continuation writes the same cache
+layout through a single-slot jitted chunk program (one multi-token
+forward over the slot's cache where the stack allows it).  KV lives in
+the paged pool of :class:`PagedKVManager` — free-list block allocator,
+per-request page tables, the same tables the Pallas ``paged_decode``
+kernel consumes.
 
 PREFIX SHARING: admission matches each prompt against the pool's token
 trie (:class:`repro.serve.kv_cache.PrefixCache`).  Matched pages are
@@ -80,6 +82,8 @@ from repro.models import (
     init_cache,
     paged_decode_supported,
     prefill,
+    prefill_chunk,
+    prefill_chunk_supported,
 )
 from repro.roofline.analysis import tick_cost_model
 from repro.serve.kv_cache import (
@@ -601,16 +605,27 @@ class ServingEngine:
             )
         )
 
+        #: chunked prefill runs each chunk as one multi-token forward over
+        #: the slot's cache where the stack allows it (plain full
+        #: attention, dense FFN), else as a per-token decode scan
+        self._chunk_batched = prefill_chunk_supported(cfg)
+        self.chunk_prefill_calls = 0  # chunk dispatches, batched forward
+        self.chunk_scan_calls = 0  # … per-token scan
+
         @jax.named_scope("chunk_scan")
         def _chunk_scan(params, tokens, caches, slot, pos0):
             """Advance ONE slot by ``len(tokens)`` prompt tokens in a
-            single device dispatch (scan over the shared decode_step) —
-            the chunked-prefill continuation path of continuous batching.
+            single device dispatch — the chunked-prefill continuation path
+            of continuous batching.  Returns the last token's logits.
 
-            Extracts the slot's cache once (keepdims → batch of 1), scans
-            the chunk through decode_step, writes the slot back, and
-            returns the last token's logits.
+            Batched path: :func:`repro.models.prefill_chunk`, one forward
+            over the chunk that writes only the slot's new K/V rows.
+            Fallback (Mamba, MLA, rings, enc-dec, shared attention, MoE):
+            extract the slot's cache once (keepdims → batch of 1), scan
+            the chunk through decode_step, write the slot back.
             """
+            if self._chunk_batched:
+                return prefill_chunk(cfg, params, tokens, caches, slot, pos0)
             take_u = lambda x: jax.lax.dynamic_index_in_dim(x, slot, 1)
             take_s = lambda x: jax.lax.dynamic_index_in_dim(x, slot, 0)
             sub = {
@@ -1804,9 +1819,13 @@ class ServingEngine:
 
         Short prompts take the monolithic fast path (one fused prefill
         call, same numerics as before); longer prompts start with one
-        budget-sized monolithic chunk and continue through the single-slot
-        decode path a chunk per tick — decode slots keep ticking in
-        between, which is the whole point of chunked prefill.
+        budget-sized monolithic chunk and continue a chunk per tick
+        through ``_chunk_scan`` — decode slots keep ticking in between,
+        which is the whole point of chunked prefill.  On stacks of plain
+        full attention with a dense FFN a chunk is one multi-token
+        forward over the slot's cache (``chunk_prefill_calls``); Mamba,
+        MLA, ring, enc-dec, shared-attention and MoE stacks scan it one
+        token at a time through ``decode_step`` (``chunk_scan_calls``).
         """
         budget = self.ecfg.prefill_chunk_tokens
         chunked = False
@@ -1872,7 +1891,8 @@ class ServingEngine:
                 # power-of-two buckets: O(log chunk) dispatches per tick
                 # and a bounded set of compiled scan widths
                 with TraceAnnotation(
-                    "engine.prefill.chunk", rid=rid, tokens=take, pos=req.pos
+                    "engine.prefill.chunk", rid=rid, tokens=take, pos=req.pos,
+                    path="batched" if self._chunk_batched else "scan",
                 ):
                     while take > 0:
                         w = 1 << (take.bit_length() - 1)
@@ -1885,6 +1905,10 @@ class ServingEngine:
                         )
                         req.pos += w
                         take -= w
+                        if self._chunk_batched:
+                            self.chunk_prefill_calls += 1
+                        else:
+                            self.chunk_scan_calls += 1
                 chunked = True
                 if not req.prefilling and last is not None:
                     self._finish_prefill(req, last)
@@ -2574,6 +2598,8 @@ class ServingEngine:
             "ticks": self.tick,
             "tick_cost": self.tick_cost_stats(),
             "chunked_prefill_ticks": self.chunked_prefill_ticks,
+            "chunk_prefill_calls": self.chunk_prefill_calls,
+            "chunk_scan_calls": self.chunk_scan_calls,
             "migrations_in": self.migrations_in,
             "migrations_out": self.migrations_out,
             "tokens_generated": sum(

@@ -103,6 +103,29 @@ def test_prefill_decode_matches_forward(arch, key):
         assert err <= tol, f"{arch} decode step {t}: err {err}"
 
 
+@pytest.mark.parametrize("prompt", [1, 2])
+def test_mamba_prompt_shorter_than_conv(prompt, key):
+    """A Mamba prefill shorter than the conv's ``d_conv - 1`` tail leaves
+    a full-size conv cache (zeros before the prompt), and decode from it
+    reproduces the full forward."""
+    cfg = ARCHS["mamba2-2.7b"].smoke()
+    assert prompt < cfg.ssm.d_conv - 1
+    params = init_model(cfg, key)
+    GEN = 3
+    tokens = _batch(cfg, key, b=2, s=prompt + GEN)["tokens"]
+    full = forward(cfg, params, tokens, remat=False)
+    _, caches = prefill(
+        cfg, params, tokens[:, :prompt], max_seq=prompt + GEN, remat=False
+    )
+    for t in range(GEN):
+        logits_d, caches = decode_step(
+            cfg, params, tokens[:, prompt + t : prompt + t + 1], caches,
+            jnp.int32(prompt + t),
+        )
+        err = float(jnp.abs(logits_d[:, 0] - full[:, prompt + t]).max())
+        assert err <= 0.15, f"decode step {t}: err {err}"
+
+
 def test_all_archs_have_param_counts_near_advertised():
     expected = {
         "granite-moe-3b-a800m": 3.3e9,

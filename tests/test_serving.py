@@ -2,11 +2,18 @@
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCHS
 from repro.sched import MursConfig
-from repro.models import init_model
+from repro.models import (
+    decode_step,
+    init_cache,
+    init_model,
+    prefill_chunk,
+    prefill_chunk_supported,
+)
 from repro.serve import EngineConfig, Request, ServingEngine
 from repro.serve.kv_cache import (
     PageBlockAllocator,
@@ -105,11 +112,22 @@ class TestPageBlockAllocator:
 
 
 class TestChunkedPrefill:
-    def test_chunked_matches_monolithic_greedy(self, small_model):
+    @pytest.mark.parametrize("arch", ["internlm2-1.8b", "mamba2-2.7b"])
+    def test_chunked_matches_monolithic_greedy(self, small_model, arch):
         """A long prompt split into chunks must generate the same greedy
         tokens as a monolithic prefill, and a co-resident short request
-        must keep decoding while the long prompt chunks through."""
-        cfg, params = small_model
+        must keep decoding while the long prompt chunks through.
+
+        internlm2 (plain attention, dense FFN) chunks through the batched
+        multi-token forward; mamba2 through the per-token decode scan,
+        whose recurrence is a different computation from its monolithic
+        prefill, so only the run's completion and the path are checked."""
+        if arch == "internlm2-1.8b":
+            cfg, params = small_model
+        else:
+            cfg = ARCHS[arch].smoke()
+            params = init_model(cfg, jax.random.PRNGKey(0))
+        batched = arch == "internlm2-1.8b"
         prompt = list(range(5, 25))  # 20 tokens
         outs = {}
         for name, chunk in (("mono", 1000), ("chunk", 6)):
@@ -124,17 +142,117 @@ class TestChunkedPrefill:
             eng.submit(Request("short", "U", list(range(3, 7)), 8))
             eng.submit(Request("long", "T", prompt, 8))
             out = eng.run(max_ticks=100).extras
+            assert sorted(eng.completed) == ["long", "short"]
             outs[name] = (
                 eng.requests["long"].generated,
                 eng.requests["short"].generated,
                 out["chunked_prefill_ticks"],
                 eng.requests["short"].finish_tick
                 < eng.requests["long"].finish_tick,
+                out["chunk_prefill_calls"],
+                out["chunk_scan_calls"],
             )
-        assert outs["mono"][0] == outs["chunk"][0]
-        assert outs["mono"][1] == outs["chunk"][1]
+        if batched:
+            assert outs["mono"][0] == outs["chunk"][0]
+            assert outs["mono"][1] == outs["chunk"][1]
         assert outs["chunk"][2] > 0 and outs["mono"][2] == 0
         assert outs["chunk"][3], "short request must finish during/ahead"
+        calls = outs["chunk"][4:] if batched else outs["chunk"][4:][::-1]
+        assert calls[0] > 0 and calls[1] == 0
+        assert outs["mono"][4:] == (0, 0)
+
+
+class TestChunkForwardParity:
+    """``prefill_chunk`` (one ``[1, w]`` forward over the slot's cache)
+    against the per-token ``decode_step`` scan it replaces."""
+
+    N_SLOTS, MAX_SEQ, SLOT = 3, 64, 1
+
+    @pytest.fixture(scope="class")
+    def setup(self, small_model):
+        cfg, params = small_model
+        # a cache full of noise: rows the chunk must not touch are visible
+        caches = jax.tree_util.tree_map(
+            lambda x: jax.random.normal(
+                jax.random.PRNGKey(1), x.shape, jnp.float32
+            ).astype(x.dtype),
+            init_cache(cfg, self.N_SLOTS, self.MAX_SEQ),
+        )
+        chunk = jax.jit(
+            lambda t, c, s, p: prefill_chunk(cfg, params, t, c, s, p)
+        )
+        step = jax.jit(
+            lambda t, c, p: decode_step(cfg, params, t[None, None], c, p)
+        )
+        return cfg, caches, chunk, step
+
+    def _scan(self, step, tokens, caches, pos0):
+        """The reference: the slot's cache as a batch of one, one
+        decode_step per token."""
+        sub = {
+            "unit": jax.tree_util.tree_map(
+                lambda x: x[:, self.SLOT:self.SLOT + 1], caches["unit"]
+            ),
+            "suffix": [],
+        }
+        for i, tok in enumerate(tokens):
+            logits, sub = step(jnp.int32(tok), sub, jnp.int32(pos0 + i))
+        return logits[0, 0], sub["unit"]
+
+    @pytest.mark.parametrize("pos0", [0, 5, 17])
+    @pytest.mark.parametrize("w", [1, 4, 8])
+    def test_matches_per_token_scan(self, setup, w, pos0):
+        self._check(setup, w, pos0)
+
+    def test_one_token_at_the_last_row(self, setup):
+        """A one-token chunk writes a two-row window; at the cache's last
+        row that window starts one row early."""
+        self._check(setup, 1, self.MAX_SEQ - 1)
+
+    def _check(self, setup, w, pos0):
+        cfg, caches, chunk, step = setup
+        assert prefill_chunk_supported(cfg) and not cfg.suffix_blocks
+        tokens = list(range(7, 7 + w))
+        ref_logits, ref_unit = self._scan(step, tokens, caches, pos0)
+        logits, out = chunk(
+            jnp.asarray(tokens, jnp.int32), caches,
+            jnp.int32(self.SLOT), jnp.int32(pos0),
+        )
+        np.testing.assert_allclose(
+            np.asarray(logits), np.asarray(ref_logits), rtol=2e-2, atol=2e-2
+        )
+        rows = slice(pos0, pos0 + w)
+        for new, ref, old in zip(
+            jax.tree_util.tree_leaves(out["unit"]),
+            jax.tree_util.tree_leaves(ref_unit),
+            jax.tree_util.tree_leaves(caches["unit"]),
+        ):
+            new = np.asarray(new.astype(jnp.float32))
+            old = np.asarray(old.astype(jnp.float32))
+            # [layers, slots, KV, seq, hd]: the chunk's rows as the scan
+            # wrote them, within bf16 rounding
+            np.testing.assert_allclose(
+                new[:, self.SLOT, :, rows],
+                np.asarray(ref.astype(jnp.float32))[:, 0, :, rows],
+                rtol=2e-2, atol=2e-2,
+            )
+            # every other row of the slot, and every other slot: the bits
+            # it had
+            mine = np.delete(new[:, self.SLOT], np.arange(pos0, pos0 + w), 2)
+            was = np.delete(old[:, self.SLOT], np.arange(pos0, pos0 + w), 2)
+            np.testing.assert_array_equal(mine, was)
+            np.testing.assert_array_equal(
+                np.delete(new, self.SLOT, 1), np.delete(old, self.SLOT, 1)
+            )
+
+    def test_stack_predicate(self):
+        """Plain full attention with a dense FFN takes the batched path;
+        MoE, Mamba, MLA, rings and enc-dec keep the per-token scan."""
+        assert prefill_chunk_supported(ARCHS["internlm2-1.8b"].smoke())
+        for arch in ("granite-moe-3b-a800m", "mamba2-2.7b",
+                     "deepseek-v2-236b", "gemma3-1b", "whisper-base",
+                     "zamba2-1.2b"):
+            assert not prefill_chunk_supported(ARCHS[arch].smoke()), arch
 
 
 class TestAdmissionLiveness:

@@ -1,8 +1,8 @@
 """Ahead-of-time compiles for a TPU v5e chip that is described, not attached.
 
-The TPU compiler is installed with jaxlib, so the paged-decode kernels and
-the full-width ``decode_step_paged`` step are lowered through Mosaic and
-XLA:TPU here, with ``JAX_PLATFORMS=cpu``.  That catches what interpret mode
+The TPU compiler is installed with jaxlib, so the paged-decode kernels, the
+full-width ``decode_step_paged`` step and the chunked-prefill forward are
+lowered through Mosaic and XLA:TPU here, with ``JAX_PLATFORMS=cpu``.  That catches what interpret mode
 cannot: block shapes the chip's tiling refuses, scalar-prefetch operands
 past the 1 MiB of SMEM, a step that does not fit the chip's HBM.  Nothing
 runs, so these tests say nothing about results or times.
@@ -11,6 +11,8 @@ The topology is described inside a module fixture (never at import time):
 only one process may load the TPU library, and every xdist worker imports
 this file.  All chip compiles stay in this one file for the same reason.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +24,12 @@ from repro.kernels.paged_decode import (
     paged_decode_attention,
     paged_decode_attention_int8,
 )
-from repro.models import decode_step_paged, init_cache, init_model
+from repro.models import (
+    decode_step_paged,
+    init_cache,
+    init_model,
+    prefill_chunk,
+)
 
 # internlm2-1.8b attention widths and the engine's 16-token pages
 H, KV, HD, PAGE = 16, 8, 128, 16
@@ -64,6 +71,21 @@ def _spec(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _full_width(one_chip, cfg, n_slots, max_seq):
+    """(params, caches) of ``cfg`` as abstract arrays on one chip."""
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda s: _spec(one_chip, s.shape, s.dtype), tree
+        )
+
+    params = place(
+        jax.eval_shape(lambda: init_model(cfg, jax.random.PRNGKey(0)))
+    )
+    caches = place(jax.eval_shape(lambda: init_cache(cfg, n_slots, max_seq)))
+    return params, caches
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize(
     "rows,width,n_pool",
@@ -98,16 +120,7 @@ def test_decode_step_paged_compiles_at_full_width(one_chip):
     2048 tokens: Mosaic kernel inside, and it fits one chip's HBM."""
     cfg = get_arch("internlm2-1.8b")
     n_slots, max_seq, rows, width, n_pool = 8, 2048, 8, 128, 1024
-
-    def place(tree):
-        return jax.tree_util.tree_map(
-            lambda s: _spec(one_chip, s.shape, s.dtype), tree
-        )
-
-    params = place(
-        jax.eval_shape(lambda: init_model(cfg, jax.random.PRNGKey(0)))
-    )
-    caches = place(jax.eval_shape(lambda: init_cache(cfg, n_slots, max_seq)))
+    params, caches = _full_width(one_chip, cfg, n_slots, max_seq)
     i32 = jnp.int32
     step = jax.jit(
         lambda p, tok, c, poss, slot, tab, lens, src_slot, src_idx: (
@@ -130,6 +143,43 @@ def test_decode_step_paged_compiles_at_full_width(one_chip):
         _spec(one_chip, (n_pool,), i32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB > one v5e's HBM"
+
+
+@pytest.mark.parametrize("w", [1, 64])
+def test_chunk_prefill_compiles_at_full_width(one_chip, w):
+    """The engine's chunked-prefill forward for full internlm2-1.8b, 8
+    slots of 2048 tokens, at the 64-token chunk width and at one token (a
+    one-row write once made XLA lay the whole cache out anew): it fits
+    one chip's HBM and copies no whole stacked cache — only the slot's new
+    rows move."""
+    cfg = get_arch("internlm2-1.8b")
+    n_slots, max_seq = 8, 2048
+    params, caches = _full_width(one_chip, cfg, n_slots, max_seq)
+    i32 = jnp.int32
+    chunk = jax.jit(
+        lambda p, tok, c, slot, pos0: prefill_chunk(cfg, p, tok, c, slot, pos0),
+        donate_argnums=(2,),
+    )
+    compiled = chunk.lower(
+        params,
+        _spec(one_chip, (w,), i32),
+        caches,
+        _spec(one_chip, (), i32),
+        _spec(one_chip, (), i32),
+    ).compile()
+    stacked = caches["unit"]["b0"][0].shape  # [layers, slots, KV, seq, hd]
+    whole = re.compile(
+        r"%(\S+) = bf16\[" + ",".join(map(str, stacked)) + r"\]\S* (\S+)\("
+    )
+    copies = [
+        m.group(0)
+        for m in whole.finditer(compiled.as_text())
+        if "copy" in m.group(1) or m.group(2).startswith("copy")
+    ]
+    assert not copies, f"whole-cache copies: {copies}"
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB > one v5e's HBM"
